@@ -74,6 +74,9 @@ def main(argv: Optional[List[str]] = None):
 
     root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env = dict(os.environ)
+    # a simulated CPU mesh by design: never compete with this process for
+    # an accelerator it may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=4").strip()

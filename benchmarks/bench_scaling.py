@@ -47,6 +47,9 @@ def run_mesh(shape_axes):
     code = _SCRIPT.format(src=os.path.abspath(src), shape_axes=shape_axes)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # a simulated CPU mesh by design: never compete with the parent for
+    # an accelerator it may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=560, env=env)
     if proc.returncode != 0:

@@ -253,6 +253,9 @@ def _mesh_cells(steps: int, global_batch: int, reps: int,
 
     root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env = dict(os.environ)
+    # a simulated CPU mesh by design: never compete with this process for
+    # an accelerator it may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={mesh_devices}").strip()

@@ -16,13 +16,14 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import NestPipeConfig, OptimizerConfig, ShapeConfig
+from ..configs.registry import ArchSpec
 from ..core.dbp.pipeline import PipelineStats
 from ..core.embedding import init_table_state
 from ..dist.checkpoint import (
@@ -125,7 +126,7 @@ class Session:
     @classmethod
     def from_arch(
         cls,
-        arch: str,
+        arch: Union[str, ArchSpec],
         *,
         mode: str = "nestpipe",
         reduced: bool = False,
@@ -159,7 +160,8 @@ class Session:
         metrics_every: Optional[int] = None,
         sparse_axes: Optional[tuple] = None,
     ) -> "Session":
-        """Resolve a registry arch into a ready session.
+        """Resolve a registry arch (by id, or an ``ArchSpec`` built outside
+        the registry) into a ready session.
 
         ``mode`` must name a registered strategy (``repro.api.strategies``).
         ``global_batch``/``seq_len`` override the named ``shape`` with a
@@ -586,7 +588,7 @@ class Session:
             npcfg = dataclasses.replace(npcfg, sparse_comm=sparse_comm)
         npcfg = strategy.configure(npcfg)
         wl = resolve(
-            self.workload.arch.name, mesh=self.workload.mesh,
+            self.workload.arch, mesh=self.workload.mesh,
             mode=self.workload.mode, npcfg=npcfg, reduced=self.reduced,
             shape_override=ShapeConfig(
                 "api-serve-emb", kind="train",

@@ -55,7 +55,11 @@ class ArchSpec:
         return False
 
 
-def get_arch(name: str) -> ArchSpec:
+def get_arch(name: Union[str, ArchSpec]) -> ArchSpec:
+    """Registry lookup by id; an :class:`ArchSpec` passes through unchanged
+    (a configuration built outside the registry, e.g. a chip-share cut)."""
+    if isinstance(name, ArchSpec):
+        return name
     if name in _LM_MODULES:
         mod = importlib.import_module(f".{_LM_MODULES[name]}", __package__)
         kind = "encdec" if mod.CONFIG.encoder is not None else "lm"

@@ -33,9 +33,8 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ...compat import shard_map
 
 from ...configs.base import NestPipeConfig
 from ...kernels import dispatch
@@ -379,9 +378,12 @@ class EmbeddingEngine:
 
         def _f(rows, accum, bkeys):
             local_idx = self._master_local_idx(bkeys)
-            brows = self._serve_rows(rows, local_idx, (bkeys.shape[0],))
+            # master precision: the buffer is updated and written back, so
+            # it must not pass through the compute dtype (_serve_rows)
+            brows = dispatch.gather_rows(rows, local_idx,
+                                         backend=self.kernel_backend)
             baccum = jnp.take(accum, local_idx, mode="fill", fill_value=0.0)
-            return DualBuffer(bkeys, brows.astype(rows.dtype), baccum)
+            return DualBuffer(bkeys, brows, baccum)
 
         f = self._smap(
             _f,

@@ -14,6 +14,7 @@ Backends
 ``"interpret"``
     The same Pallas kernels under the Pallas interpreter. Slow; exists so
     the exact kernel code paths can be validated on CPU (tests use this).
+    Refused on a TPU host, where it would hide the compiled kernels.
 ``"reference"``
     The pure-jnp oracles from ``ref.py`` — the fastest choice on CPU and
     the ground truth the kernels are swept against.
@@ -34,16 +35,16 @@ All three ops keep the engine's sentinel conventions regardless of backend:
   re-masks so callers never see clamp artifacts.
 - ``segment_rowsum(values, ids, num_segments)``: rows with
   ``ids >= num_segments`` are dropped; accumulation is f32 regardless of
-  the input dtype. Ids do NOT have to be sorted — the one-hot-matmul kernel
-  is order-independent; sortedness (which the engine's routing guarantees
-  where it matters) only improves its output-tile locality.
+  the input dtype. Ids do NOT have to be sorted (the kernel sorts them).
 - ``buffer_sync(active_rows, prefetch_rows, src)``: per prefetch row,
   ``src[i] < len(active_rows)`` selects the active row, anything else keeps
   the prefetch row.
 
-Each op is bit-identical across backends for f32 inputs (asserted by
-``tests/test_dispatch.py``), so swapping backends is purely a performance
-decision — never a numerics one.
+``gather_rows`` and ``buffer_sync`` move rows without arithmetic, so they
+are bit-identical across backends. ``segment_rowsum`` is bit-identical
+whenever f32 sums its rows exactly (``tests/test_dispatch.py`` checks
+integer-valued rows); otherwise its sum order differs from the scatter-add
+reference and results agree to f32 rounding.
 """
 from __future__ import annotations
 
@@ -56,23 +57,21 @@ import jax.numpy as jnp
 from . import ref
 from .buffer_sync import buffer_sync_rows as _buffer_sync_kernel
 from .embedding_gather import embedding_gather as _gather_kernel
-from .segment_rowsum import segment_rowsum_sorted as _segsum_kernel
+from .segment_rowsum import segment_rowsum as _segsum_kernel
 
 BACKENDS = ("pallas", "interpret", "reference")
 
 _default_override: Optional[str] = None
 
 
-def _auto_backend() -> str:
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "reference"
-    except Exception:
-        return "reference"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def resolve_backend(backend: Optional[str] = None) -> str:
     """Resolve a backend name: explicit arg > set_default_backend() >
-    $REPRO_KERNEL_BACKEND > auto-detect. ``"auto"``/None fall through."""
+    $REPRO_KERNEL_BACKEND > auto-detect. ``"auto"``/None fall through.
+    On a TPU host ``"interpret"`` is refused."""
     for cand in (backend, _default_override,
                  os.environ.get("REPRO_KERNEL_BACKEND")):
         if cand and cand != "auto":
@@ -80,8 +79,12 @@ def resolve_backend(backend: Optional[str] = None) -> str:
                 raise ValueError(
                     f"unknown kernel backend {cand!r}; expected one of "
                     f"{BACKENDS} or 'auto'")
+            if cand == "interpret" and _on_tpu():
+                raise ValueError(
+                    "kernel backend 'interpret' is refused on a TPU host: "
+                    "use 'pallas' (compiled kernels) or 'reference'")
             return cand
-    return _auto_backend()
+    return "pallas" if _on_tpu() else "reference"
 
 
 def set_default_backend(backend: Optional[str]) -> None:
@@ -116,8 +119,8 @@ def segment_rowsum(values: jax.Array, ids: jax.Array, num_segments: int, *,
     b = resolve_backend(backend)
     if b == "reference":
         return ref.segment_rowsum_ref(values, ids, num_segments)
-    return _segsum_kernel(values.astype(jnp.float32), ids.astype(jnp.int32),
-                          num_segments, interpret=(b != "pallas"))
+    return _segsum_kernel(values, ids, num_segments,
+                          interpret=(b != "pallas"))
 
 
 def buffer_sync(active_rows: jax.Array, prefetch_rows: jax.Array,

@@ -75,7 +75,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, tq, h, hd = q.shape
     tk = k.shape[1]

@@ -61,7 +61,7 @@ def hstu_attention(
     causal: bool = True,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, t, h, dqk = q.shape
     dv = v.shape[-1]

@@ -6,7 +6,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -248,7 +248,7 @@ class Workload:
 
 
 def resolve(
-    arch_name: str,
+    arch_name: Union[str, ArchSpec],
     shape_name: str = "train_4k",
     *,
     mesh: Optional[Mesh] = None,
@@ -262,6 +262,7 @@ def resolve(
     sparse_axes: Optional[Tuple[str, ...]] = None,
 ) -> Workload:
     arch = get_arch(arch_name)
+    arch_name = arch.name
     if shape_override is not None:
         shape = shape_override
     elif arch.kind == "recsys":

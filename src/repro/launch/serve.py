@@ -18,6 +18,7 @@ import json
 
 from ..api import Session
 from ..configs.registry import get_arch
+from .compile_cache import enable_compile_cache
 
 
 def serve(argv=None):
@@ -46,6 +47,7 @@ def serve(argv=None):
     p.add_argument("--train-steps", type=int, default=0,
                    help="warm the table with N training steps before serving")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     if get_arch(args.arch).kind == "recsys":
         sess = Session.from_arch(

@@ -16,6 +16,7 @@ import signal
 
 from ..api import Session, available_strategies
 from ..core.store import STORES
+from .compile_cache import enable_compile_cache
 
 
 def train(argv=None):
@@ -40,6 +41,7 @@ def train(argv=None):
     p.add_argument("--prefetch-ahead", type=int, default=1,
                    help="DBP retrieval lookahead depth k")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     # CPU-scale run: no mesh (single device); the production-mesh config is
     # proven by the dry-run.

@@ -465,7 +465,7 @@ def apply_moe_ep_shardmap(params, x, cfg: MoEConfig, mlp_type: str,
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
 
     if slack is None:
         slack = cfg.capacity_factor

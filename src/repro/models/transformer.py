@@ -17,9 +17,8 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..compat import shard_map
 
 from ..configs.base import ModelConfig, ParallelConfig
 from ..utils import cdiv

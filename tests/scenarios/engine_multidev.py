@@ -68,9 +68,8 @@ def run_case(name, mesh, sparse_axes, keys_pspec, keys_shape):
     print(f"  [{name}] serial update exact: {ok}")
     assert ok
 
-from repro.compat import make_auto_mesh
-
-mesh_lm = make_auto_mesh((2, 4), ("data", "model"))
+mesh_lm = jax.make_mesh((2, 4), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 # LM: keys (B, T), batch over data, seq over model
 run_case("lm", mesh_lm, ("model",), P("data", "model"), (4, 8))
 # recsys: flat keys (B*F,), batch over everything

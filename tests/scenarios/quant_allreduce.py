@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.dist.compressed import ring_allreduce_quant
 

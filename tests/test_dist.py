@@ -106,7 +106,7 @@ def test_retry_step_exhausts():
 def test_ring_allreduce_quant_single_axis():
     """Degenerate 1-device ring: exact identity."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("d",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(17,)), jnp.float32)
 
@@ -124,7 +124,7 @@ def test_ring_allreduce_quant_arbitrary_shapes():
     """Non-1-D leaves ravel through the ring and reshape back: shape and
     (1-device) values preserved exactly, residual zero."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("d",))
     rng = np.random.default_rng(1)
     for shape in ((4, 5), (2, 3, 7), (1, 1), (6,)):
@@ -142,7 +142,7 @@ def test_ring_allreduce_quant_tree():
     """Pytree lift: every leaf reduced, structure preserved on both the
     summed tree and the residual tree."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.dist import ring_allreduce_quant_tree
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("d",))
     rng = np.random.default_rng(2)
