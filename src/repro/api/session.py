@@ -392,12 +392,10 @@ class Session:
             self.save()
 
         summary = stats.summary()
-        gb = self.workload.shape.global_batch
         summary.update({
             "arch": self.workload.arch.name,
             "mode": self.strategy.name,
             "wall_s": round(wall, 2),
-            "qps": round(gb * len(stats.step_times) / max(wall, 1e-9), 2),
             "stragglers_flagged": flagged,
         })
         return TrainReport(state=state, stats=stats, wall_s=wall,

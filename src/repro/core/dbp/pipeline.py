@@ -72,6 +72,15 @@ Hot-loop discipline (this is the part the paper's overlap depends on):
   straggler EMA are computed from drained timestamps: every step in a
   drained span is attributed the span's mean wall time (minus host
   input-wait), so straggler detection operates at drain granularity.
+
+Profiler spans (``jax.profiler``; each an inactive ``TraceMe`` when no
+trace runs): ``dbp.input_wait`` around the queue read, ``dbp.h2d`` around
+the batch's device_put, ``dbp.window`` (a step annotation carrying the
+step number) around each window dispatch, ``dbp.sync`` around stage 4b,
+``dbp.drain`` around the metric drain's block and host conversion, and
+the store's ``dbp.plan`` / ``dbp.retrieve`` / ``dbp.commit`` / ``dbp.h2d``
+from its stage timers. Per-step host timestamps thus come from the trace,
+not from the drain's span means.
 """
 from __future__ import annotations
 
@@ -104,7 +113,12 @@ from ..store import (
 class PipelineStats:
     step_times: List[float] = field(default_factory=list)
     losses: List[float] = field(default_factory=list)
-    h2d_times: List[float] = field(default_factory=list)
+    # sum over the drained steps of the window's unique keys (the
+    # non-sentinel rows of the dual buffer), and the buffer's static row
+    # capacity K: the rows retrieve gathers and sync_buffers copies each
+    # step (0 in serial mode, which has no buffer)
+    buffer_keys_valid: int = 0
+    buffer_rows: int = 0
     input_wait_times: List[float] = field(default_factory=list)
     input_wait_total: float = 0.0  # running sum (the drain reads it per
     # span; recomputing sum(input_wait_times) there was O(steps^2))
@@ -144,8 +158,6 @@ class PipelineStats:
         out = {
             "steps": len(self.step_times),
             "mean_step_s": float(st.mean()) if len(st) else 0.0,
-            "p50_step_s": float(np.percentile(st, 50)) if len(st) else 0.0,
-            "p99_step_s": float(np.percentile(st, 99)) if len(st) else 0.0,
             "mean_input_wait_s": float(np.mean(self.input_wait_times or [0.0])),
             "stragglers": len(self.straggler_steps),
             "final_loss": self.losses[-1] if self.losses else float("nan"),
@@ -213,29 +225,31 @@ class _MetricsDrain:
             self._wait_mark = self.stats.input_wait_total
             self._snapshot_store()
             return
-        jax.block_until_ready(self.pending[-1][1])
-        now = time.perf_counter()
-        waited = self.stats.input_wait_total - self._wait_mark
-        dt = max(now - self._t_mark - waited, 0.0) / len(self.pending)
-        for t, aux in self.pending:
-            self.stats.step_times.append(dt)
-            self.stats.losses.append(float(aux["loss"]))
-            self.stats.overflow_max = max(
-                self.stats.overflow_max, int(aux.get("routing_overflow", 0))
-            )
-            if self.watchdog is not None:
-                if self.watchdog.observe(t, dt):
-                    self.stats.straggler_steps.append(t)
-            else:
-                if self.ema is not None and \
-                        dt > self.straggler_factor * self.ema:
-                    self.stats.straggler_steps.append(t)
-                self.ema = dt if self.ema is None else \
-                    0.9 * self.ema + 0.1 * dt
+        with jax.profiler.TraceAnnotation("dbp.drain"):
+            jax.block_until_ready(self.pending[-1][1])
+            now = time.perf_counter()
+            waited = self.stats.input_wait_total - self._wait_mark
+            dt = max(now - self._t_mark - waited, 0.0) / len(self.pending)
+            for t, aux in self.pending:
+                self._record(t, aux, dt)
         self.pending.clear()
         self._t_mark = now
         self._wait_mark = self.stats.input_wait_total
         self._snapshot_store()
+
+    def _record(self, t: int, aux, dt: float) -> None:
+        self.stats.step_times.append(dt)
+        self.stats.losses.append(float(aux["loss"]))
+        self.stats.overflow_max = max(
+            self.stats.overflow_max, int(aux.get("routing_overflow", 0)))
+        self.stats.buffer_keys_valid += int(aux.get("buffer_keys_valid", 0))
+        if self.watchdog is not None:
+            if self.watchdog.observe(t, dt):
+                self.stats.straggler_steps.append(t)
+        else:
+            if self.ema is not None and dt > self.straggler_factor * self.ema:
+                self.stats.straggler_steps.append(t)
+            self.ema = dt if self.ema is None else 0.9 * self.ema + 0.1 * dt
 
     def push(self, t: int, aux) -> None:
         self.pending.append((t, aux))
@@ -336,15 +350,14 @@ class DBPDriver:
     # -- stages 1-2 -----------------------------------------------------
 
     def _next_device_batch(self, stats: PipelineStats):
-        t0 = time.perf_counter()
-        host_batch = self.queue.get()
-        stats.add_input_wait(time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation("dbp.input_wait"):
+            t0 = time.perf_counter()
+            host_batch = self.queue.get()
+            stats.add_input_wait(time.perf_counter() - t0)
         if self.device_fields is not None:
             host_batch = {k: host_batch[k] for k in self.device_fields}
-        t1 = time.perf_counter()
-        dev = stage_to_device(host_batch, self.batch_shardings or {})
-        stats.h2d_times.append(time.perf_counter() - t1)
-        return dev
+        with jax.profiler.TraceAnnotation("dbp.h2d"):
+            return stage_to_device(host_batch, self.batch_shardings or {})
 
     # -- main loop --------------------------------------------------------
 
@@ -358,7 +371,9 @@ class DBPDriver:
             if self.mode == "serial":
                 for t in range(num_steps):
                     batch = self._next_device_batch(stats)
-                    state, aux, pkts = self._jit_serial(state, batch)
+                    with jax.profiler.StepTraceAnnotation("dbp.window",
+                                                          step_num=t):
+                        state, aux, pkts = self._jit_serial(state, batch)
                     state = state._replace(
                         table=self._jit_commit_pkts(state.table, pkts))
                     drain.push(t, aux)
@@ -398,20 +413,24 @@ class DBPDriver:
             pf.fill(limit=num_steps)  # windows 0..min(k,N)-1
             first = pf.pop()  # warm-up: route + retrieve batch 0
             carry = PipelineCarry(first.buffer, first.plan.window)
+            stats.buffer_rows = int(first.buffer.keys.shape[0])
             cur_plan, batch = first.plan, first.batch
             for t in range(num_steps):
                 # stages 3+4 for t+1..t+k overlap this window; capped so a
                 # finite run never retrieves windows no step consumes
                 pf.fill(limit=num_steps - 1 - t)
-                state, aux, buf_updated = self._jit_window(
-                    state, carry.buffer, carry.plan, batch)
+                with jax.profiler.StepTraceAnnotation("dbp.window",
+                                                      step_num=t):
+                    state, aux, buf_updated = self._jit_window(
+                        state, carry.buffer, carry.plan, batch)
                 if t + 1 < num_steps:
                     nxt = pf.pop()
                     if sync_on:
                         # stage 4b: repair the t+1 buffer (and every deeper
                         # in-flight buffer) against this window's updates.
-                        nxt_buf = self._jit_sync(buf_updated, nxt.buffer)
-                        pf.resync(buf_updated, self._jit_sync)
+                        with jax.profiler.TraceAnnotation("dbp.sync"):
+                            nxt_buf = self._jit_sync(buf_updated, nxt.buffer)
+                            pf.resync(buf_updated, self._jit_sync)
                     else:
                         nxt_buf = nxt.buffer  # staleness baseline: no sync
                 commit(buf_updated, cur_plan)  # stage 6 (inline or queued)

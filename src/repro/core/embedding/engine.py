@@ -549,3 +549,9 @@ class EmbeddingEngine:
             else plan_or_window.overflow
         )
         return jnp.max(ovf)
+
+    def buffer_keys_valid(self, buffer: DualBuffer) -> jax.Array:
+        """The buffer's non-sentinel keys, summed over shards: the window's
+        unique keys, of the ``buffer.keys.shape[0]`` rows that retrieval
+        gathers and the dual-buffer sync copies."""
+        return jnp.sum(buffer.keys != SENTINEL, dtype=jnp.int32)

@@ -5,6 +5,12 @@ Builds the jittable window function that runs N micro-batches through
 update until the window closes — the parameter-freezing phenomenon that
 makes the overlap semantically free (Prop. 2).
 
+Named scopes split the window in the HLO's ``op_name`` metadata:
+``fwp_sparse`` on the embedding lookup, the gradient packets and (in
+``train/step.py``) the buffer update; ``fwp_optimizer`` on the dense
+update; the backbones put ``fwp_attention`` on their attention block. A
+scope changes metadata only: no jit is split and nothing is renamed.
+
 Overlap realization on TPU (DESIGN.md §2): with ``unroll=True`` the window
 is straight-line HLO, so the embedding All2All of micro-batch i+1 has no
 data dependency on the dense compute of micro-batch i and XLA's
@@ -57,11 +63,14 @@ def build_fwp_window(
     grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
 
     def one_micro(dense_params, buffer: DualBuffer, plan: LookupPlan, mb):
-        emb = engine.lookup_from_buffer(buffer, plan, mb_keys_shape, n_micro)
+        with jax.named_scope("fwp_sparse"):
+            emb = engine.lookup_from_buffer(buffer, plan, mb_keys_shape,
+                                            n_micro)
         (loss, metrics), (dgrads, demb) = grad_fn(dense_params, emb, mb)
         # 1/N so the window total is the batch-mean gradient.
         demb = demb * (1.0 / n_micro)
-        packet = engine.grads_to_owner(plan, demb, mb_keys_shape, n_micro)
+        with jax.named_scope("fwp_sparse"):
+            packet = engine.grads_to_owner(plan, demb, mb_keys_shape, n_micro)
         return loss, metrics, tree_scale(dgrads, 1.0 / n_micro), packet
 
     if unroll:
@@ -73,8 +82,9 @@ def build_fwp_window(
             for i in range(n_micro):
                 plan_i = jax.tree.map(lambda x: x[i], window_plan.plans)
                 mb_i = jax.tree.map(lambda x: x[i], mb_batches)
-                emb = engine.lookup_from_buffer(buffer, plan_i, mb_keys_shape,
-                                                n_micro)
+                with jax.named_scope("fwp_sparse"):
+                    emb = engine.lookup_from_buffer(buffer, plan_i,
+                                                    mb_keys_shape, n_micro)
                 if gate is not None:
                     # Two-stream schedule (paper Fig. 5): the embedding All2All
                     # of micro-batch i (communication stream) has no dependency
@@ -89,7 +99,9 @@ def build_fwp_window(
                 # barrier orders bwd(i) before fwd(i+1), not just fwd(i).
                 gate = demb.ravel()[0] * 0.0 + loss
                 demb = demb * (1.0 / n_micro)
-                pkt = engine.grads_to_owner(plan_i, demb, mb_keys_shape, n_micro)
+                with jax.named_scope("fwp_sparse"):
+                    pkt = engine.grads_to_owner(plan_i, demb, mb_keys_shape,
+                                                n_micro)
                 dg = tree_scale(dg, 1.0 / n_micro)
                 losses.append(loss)
                 all_metrics.append(metrics)

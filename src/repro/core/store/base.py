@@ -48,6 +48,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, NamedTuple, Optional, Protocol, runtime_checkable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -68,6 +69,11 @@ STAGE_TIMER_KEYS = ("plan_ms", "retrieve_ms", "commit_ms", "h2d_ms")
 class StageTimers:
     """Cumulative per-stage wall-time counters (milliseconds).
 
+    Each timed interval is also a profiler span named ``dbp.<stage>``
+    (``dbp.plan``, ``dbp.retrieve``, ``dbp.commit``, ``dbp.h2d``), on the
+    clock of the device ops when a trace is running; with none running a
+    span costs one inactive ``TraceMe``.
+
     Thread-safe: with the async stage executor, plan/retrieve run on stage
     threads while commit runs on the commit thread, so increments race.
     """
@@ -82,11 +88,12 @@ class StageTimers:
 
     @contextmanager
     def timed(self, key: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(key, time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation("dbp." + key.removesuffix("_ms")):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(key, time.perf_counter() - t0)
 
     def as_dict(self) -> Dict[str, float]:
         with self._lock:

@@ -87,7 +87,9 @@ def fuxi_forward(params, cfg: RecsysModelConfig, emb: jax.Array) -> jax.Array:
     @jax.checkpoint  # remat: only layer-boundary residuals survive to bwd
     def body_fn(x, lp):
         h = L.apply_norm(lp["norm1"], x, cfg.norm_eps)
-        x = x + L.gqa_attention(lp["attn"], h, acfg, positions=positions)
+        with jax.named_scope("fwp_attention"):
+            a = L.gqa_attention(lp["attn"], h, acfg, positions=positions)
+        x = x + a
         h = L.apply_norm(lp["norm2"], x, cfg.norm_eps)
         v = h @ lp["w_up"]
         base = v

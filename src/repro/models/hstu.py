@@ -72,27 +72,29 @@ def _hstu_layer(p, x, h: int, dqk: int, dv: int, eps: float, q_chunk: int = 256)
     b, s, d = x.shape
     n = L.apply_norm(p["norm"], x, eps)
     mixed = jax.nn.silu(n @ p["w_uvqk"])
-    u, v, q, k = jnp.split(
-        mixed.reshape(b, s, h, 2 * dqk + 2 * dv),
-        [dv, 2 * dv, 2 * dv + dqk],
-        axis=-1,
-    )
-    # Pointwise (no-softmax) aggregation streams trivially: process query
-    # chunks so the (b,h,qc,s) score block bounds memory, causal-sliced keys.
-    qc = max(q_chunk, -(-s // 8))  # <=8 unrolled chunks (compile hygiene)
-    outs = []
-    for i in range(0, s, qc):
-        qi = q[:, i : i + qc]
-        kv_len = min(s, i + qi.shape[1])
-        ki = k[:, :kv_len]
-        vi = v[:, :kv_len]
-        scores = jnp.einsum("bqhd,bkhd->bhqk", qi, ki) / (dqk ** 0.5)
-        a = jax.nn.silu(scores)
-        q_pos = jnp.arange(qi.shape[1]) + i
-        k_pos = jnp.arange(kv_len)
-        a = jnp.where(q_pos[:, None] >= k_pos[None, :], a, 0.0) / s
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd", a, vi))
-    y = jnp.concatenate(outs, axis=1).reshape(b, s, h * dv)
+    with jax.named_scope("fwp_attention"):
+        u, v, q, k = jnp.split(
+            mixed.reshape(b, s, h, 2 * dqk + 2 * dv),
+            [dv, 2 * dv, 2 * dv + dqk],
+            axis=-1,
+        )
+        # Pointwise (no-softmax) aggregation streams trivially: process
+        # query chunks so the (b,h,qc,s) score block bounds memory,
+        # causal-sliced keys.
+        qc = max(q_chunk, -(-s // 8))  # <=8 unrolled chunks (compile hygiene)
+        outs = []
+        for i in range(0, s, qc):
+            qi = q[:, i : i + qc]
+            kv_len = min(s, i + qi.shape[1])
+            ki = k[:, :kv_len]
+            vi = v[:, :kv_len]
+            scores = jnp.einsum("bqhd,bkhd->bhqk", qi, ki) / (dqk ** 0.5)
+            a = jax.nn.silu(scores)
+            q_pos = jnp.arange(qi.shape[1]) + i
+            k_pos = jnp.arange(kv_len)
+            a = jnp.where(q_pos[:, None] >= k_pos[None, :], a, 0.0) / s
+            outs.append(jnp.einsum("bhqk,bkhd->bqhd", a, vi))
+        y = jnp.concatenate(outs, axis=1).reshape(b, s, h * dv)
     y = L.apply_norm(p["out_norm"], y, eps) * u.reshape(b, s, h * dv)
     return x + y @ p["w_o"]
 

@@ -199,15 +199,18 @@ def build_step_fns(
         warnings."""
         out = window_fn(state.dense, buffer, plan, batch)
         lr = lr_sched(state.step)
-        new_dense, new_opt, gnorm = optimizer.update(
-            state.dense, state.opt, reduce_dense(out.dense_grads), lr
-        )
-        buf_updated = engine.apply_window_to_buffer(buffer, out.packets)
+        with jax.named_scope("fwp_optimizer"):
+            new_dense, new_opt, gnorm = optimizer.update(
+                state.dense, state.opt, reduce_dense(out.dense_grads), lr
+            )
+        with jax.named_scope("fwp_sparse"):
+            buf_updated = engine.apply_window_to_buffer(buffer, out.packets)
         aux = {
             "loss": out.loss,
             "grad_norm": gnorm,
             "lr": lr,
             "routing_overflow": engine.overflow_metric(plan),
+            "buffer_keys_valid": engine.buffer_keys_valid(buffer),
             **out.metrics,
         }
         new_state = TrainState(new_dense, new_opt, state.table, state.step + 1)

@@ -14,6 +14,7 @@ process may hold the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 import sys
 
 import jax
@@ -127,3 +128,32 @@ def test_buffer_sync(one_chip, dim):
     k = d.buffer_cap
     compile_pallas(one_chip, dispatch.buffer_sync, ((k, dim), jnp.float32),
                    ((k, dim), jnp.float32), ((k,), jnp.int32))
+
+
+def test_window_program_keeps_kernel_names(one_chip):
+    """The named scopes of the FWP window (``fwp_sparse`` around the lookup
+    and the gradient packets) change metadata only: the compiled window
+    program still names its Pallas calls after their jitted wrappers, as
+    the benchmark's kernel readers match them."""
+    from repro.api import Session
+
+    sess = Session.from_arch(
+        "hstu-industrial", mode="nestpipe", reduced=True, global_batch=8,
+        seq_len=32, n_micro=2, npcfg=NestPipeConfig(kernel_backend="pallas"))
+    batch = {k: jax.ShapeDtypeStruct(*v)
+             for k, v in sess.workload.batch_shapes.items()}
+    buf, plan = jax.eval_shape(sess.fns.init_carry, sess.state.table,
+                               batch["keys"])
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (sess.state, buf, plan, batch))
+    text = jax.jit(sess.fns.window_step).lower(*args).compile().as_text()
+    calls = [l.split(" = ", 1)[0].strip().lstrip("%")
+             for l in text.splitlines() if "tpu_custom_call" in l
+             and " = " in l]
+    for marker in ("embedding_gather", "segment_rowsum"):
+        named = [c for c in calls if re.fullmatch(marker + r"\.\d+", c)]
+        assert named, (marker, calls)
+    # the scope is in the calls' op_name path, not in their names
+    paths = re.findall(r'op_name="([^"]*pallas_call)"', text)
+    assert paths and all("fwp_sparse" in p.split("/") for p in paths), paths
