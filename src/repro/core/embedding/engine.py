@@ -42,10 +42,9 @@ from ...utils import cdiv, round_up
 from .routing import (
     SENTINEL,
     bucket_by_owner_window,
+    fixed_unique,
     fixed_unique_window,
     intersect_sorted,
-    merge_sorted_unique,
-    sorted_lookup,
 )
 from .table import EmbeddingTableState, MegaTableSpec
 
@@ -56,6 +55,10 @@ class LookupPlan(NamedTuple):
     inverse: jax.Array  # (L,) position -> unique slot (U for invalid)
     slot_of_unique: jax.Array  # (U,) unique slot -> flat send slot (S*C for invalid)
     recv_keys: jax.Array  # (S, C) keys this shard must serve (owner side)
+    # (S, C) row each received key is served from: its slot in the window's
+    # buffer keys (K for a sentinel) for a route_window plan; its row of the
+    # master shard (rows_per_shard for a sentinel) for a plan routed alone
+    buffer_slot: jax.Array
     overflow: jax.Array  # () int32 routing overflow (must be 0)
 
 
@@ -70,6 +73,7 @@ class GradPacket(NamedTuple):
     """Owner-side gradient fragment produced by one micro-batch's All2All."""
 
     keys: jax.Array  # (S, C) int32
+    buffer_slot: jax.Array  # (S, C) int32, the plan's buffer_slot
     grads: jax.Array  # (S, C, D) f32
 
 
@@ -220,8 +224,13 @@ class EmbeddingEngine:
     def _shard_id(self):
         if self.mesh is None or self.num_shards == 1:
             return jnp.int32(0)
+        return self._axes_index(self.sparse_axes)
+
+    def _axes_index(self, axes: Tuple[str, ...]):
+        """This device's axis-0-major flat index over ``axes``: its block of
+        a tiled ``all_gather`` over them."""
         idx = jnp.int32(0)
-        for a in self.sparse_axes:
+        for a in axes:
             idx = idx * self.mesh.shape[a] + jax.lax.axis_index(a)
         return idx
 
@@ -248,7 +257,12 @@ class EmbeddingEngine:
 
     def _plan_pspecs(self) -> LookupPlan:
         s = self._local_spec()
-        return LookupPlan(inverse=s, slot_of_unique=s, recv_keys=s, overflow=s)
+        return LookupPlan(inverse=s, slot_of_unique=s, recv_keys=s,
+                          buffer_slot=s, overflow=s)
+
+    def _packet_pspecs(self) -> GradPacket:
+        s = self._local_spec()
+        return GradPacket(keys=s, buffer_slot=s, grads=s)
 
     def _stack(self, pspec_tree, extra_dims=1):
         """Prefix ``extra_dims`` None axes (stacked micro-batch leading dims)."""
@@ -280,6 +294,7 @@ class EmbeddingEngine:
             inverse=uniq.inverse,
             slot_of_unique=buck.slot_of_unique,
             recv_keys=recv_per_mb,
+            buffer_slot=self._master_local_idx(recv_per_mb),
             overflow=(uniq.overflow + buck.overflow)[:, None],  # (N, 1)
         )
 
@@ -291,16 +306,25 @@ class EmbeddingEngine:
 
     def _route_window_local(self, keys: jax.Array, dims: EngineDims) -> WindowPlan:
         """Route all N micro-batches in one fused pass, then union the
-        owner-side key sets (over micro-batches AND replicated axes)."""
+        owner-side key sets (over micro-batches AND replicated axes). The
+        union's inverse is each received key's buffer slot: the window
+        serves and updates the buffer at those slots, with no search."""
         plans = self._route_plans(keys.reshape(dims.n_micro, -1), dims)
 
         all_keys = plans.recv_keys.reshape(-1)
+        n_local = all_keys.shape[0]
         if self.psum_axes:
             # Union over replicated axes so buffers are replica-identical.
             gathered = jax.lax.all_gather(all_keys, self.psum_axes, tiled=True)
             all_keys = gathered.reshape(-1)
-        buffer_keys = merge_sorted_unique(all_keys, dims.buffer_cap)
-        return WindowPlan(plans, buffer_keys)
+        union = fixed_unique(all_keys, dims.buffer_cap)
+        slot = union.inverse
+        if self.psum_axes:
+            # this device's own block of the gathered keys
+            start = self._axes_index(self.psum_axes) * n_local
+            slot = jax.lax.dynamic_slice_in_dim(slot, start, n_local)
+        plans = plans._replace(buffer_slot=slot.reshape(plans.recv_keys.shape))
+        return WindowPlan(plans, union.unique_keys)
 
     def _serve_rows(self, rows_src: jax.Array, local_idx: jax.Array,
                     shape: Tuple[int, ...]) -> jax.Array:
@@ -331,17 +355,17 @@ class EmbeddingEngine:
         send = jnp.zeros((dims.num_shards * dims.cap, demb.shape[-1]), jnp.float32)
         send = send.at[plan.slot_of_unique].set(uniq_grads, mode="drop")
         recv = self._a2a(send.reshape(dims.num_shards, dims.cap, -1))
-        return GradPacket(keys=plan.recv_keys, grads=recv)
+        return GradPacket(keys=plan.recv_keys, buffer_slot=plan.buffer_slot,
+                          grads=recv)
 
     def _window_grads_to_buffer_space(
-        self, buffer_keys: jax.Array, packets: GradPacket
+        self, buffer_slot: jax.Array, grads: jax.Array, num_rows: int
     ) -> jax.Array:
-        """Segment all window packets into buffer space and combine replicas."""
-        flat_keys = packets.keys.reshape(-1)
-        flat_grads = packets.grads.reshape(-1, packets.grads.shape[-1])
-        idx = sorted_lookup(buffer_keys, flat_keys)
+        """Segment all window packets into buffer space (by each key's
+        buffer slot from routing) and combine replicas."""
+        flat_grads = grads.reshape(-1, grads.shape[-1])
         total = dispatch.segment_rowsum(
-            flat_grads, idx, buffer_keys.shape[0],
+            flat_grads, buffer_slot.reshape(-1), num_rows,
             backend=self.kernel_backend)  # (K, D) f32
         if self.psum_axes:
             total = jax.lax.psum(total, self.psum_axes)
@@ -417,20 +441,19 @@ class EmbeddingEngine:
     ) -> jax.Array:
         """FWP forward for one micro-batch: embedding All2All served from the
         (synced) buffer. Returns local embeddings (*keys_shape, D)."""
-        dims = self.dims(keys_shape, n_micro)
         b_specs = self._buffer_pspecs()
         p_specs = self._plan_pspecs()
         out_spec = P(*tuple(self.keys_pspec) + (None,))
 
-        def _f(bk, br, ba, inverse, slots, recv_keys, overflow):
-            plan_l = LookupPlan(inverse, slots, recv_keys, overflow)
-            idx = sorted_lookup(bk, recv_keys.reshape(-1))
-            served = self._serve_rows(br, idx, recv_keys.shape)
+        def _f(br, *plan_leaves):
+            plan_l = LookupPlan(*plan_leaves)
+            served = self._serve_rows(br, plan_l.buffer_slot,
+                                      plan_l.recv_keys.shape)
             emb = self._assemble(plan_l, served)
             return emb.reshape(*[s for s in self._local_shape(keys_shape)], -1)
 
-        f = self._smap(_f, tuple(b_specs) + tuple(p_specs), out_spec)
-        return f(*buffer, *plan)
+        f = self._smap(_f, (b_specs.rows,) + tuple(p_specs), out_spec)
+        return f(buffer.rows, *plan)
 
     def lookup_from_master(
         self, table: EmbeddingTableState, keys: jax.Array
@@ -443,8 +466,8 @@ class EmbeddingEngine:
 
         def _f(rows, accum, k):
             plan = self._route_one(k.reshape(-1), dims)
-            local_idx = self._master_local_idx(plan.recv_keys)
-            served = self._serve_rows(rows, local_idx, plan.recv_keys.shape)
+            served = self._serve_rows(rows, plan.buffer_slot,
+                                      plan.recv_keys.shape)
             emb = self._assemble(plan, served)
             return emb.reshape(*self._local_shape(keys.shape), -1), plan
 
@@ -472,35 +495,37 @@ class EmbeddingEngine:
         dims = self.dims(keys_shape, n_micro)
         p_specs = self._plan_pspecs()
         demb_spec = P(*tuple(self.keys_pspec) + (None,))
-        out_specs = GradPacket(keys=self._local_spec(), grads=self._local_spec())
 
-        def _f(inverse, slots, recv_keys, overflow, g):
-            plan_l = LookupPlan(inverse, slots, recv_keys, overflow)
-            return self._grads_out(plan_l, g.reshape(-1, g.shape[-1]), dims)
+        def _f(*leaves):
+            *plan_leaves, g = leaves
+            return self._grads_out(LookupPlan(*plan_leaves),
+                                   g.reshape(-1, g.shape[-1]), dims)
 
-        f = self._smap(_f, tuple(p_specs) + (demb_spec,), out_specs)
+        f = self._smap(_f, tuple(p_specs) + (demb_spec,),
+                       self._packet_pspecs())
         return f(*plan, demb)
 
     def apply_window_to_buffer(
         self, buffer: DualBuffer, packets: GradPacket
     ) -> DualBuffer:
-        """Frozen-window end: aggregate all packets by key, psum across
+        """Frozen-window end: aggregate all packets by buffer slot, psum across
         replicas, apply rowwise adagrad once to the active buffer."""
         b_specs = self._buffer_pspecs()
-        pkt_specs = self._stack(GradPacket(self._local_spec(), self._local_spec()))
+        pkt_specs = self._stack(self._packet_pspecs())
 
-        def _f(bk, br, ba, pkeys, pgrads):
-            total = self._window_grads_to_buffer_space(
-                bk, GradPacket(pkeys, pgrads)
-            )
+        def _f(bk, br, ba, pslot, pgrads):
+            total = self._window_grads_to_buffer_space(pslot, pgrads,
+                                                       bk.shape[0])
             touched = jnp.any(total != 0.0, axis=-1)
             # Count-based touched is wrong for exactly-zero grads; that only
             # skips a zero update, which is a no-op anyway.
             rows, accum = self._rowwise_adagrad(br, ba, total, touched)
             return DualBuffer(bk, rows, accum)
 
-        f = self._smap(_f, tuple(b_specs) + tuple(pkt_specs), b_specs)
-        return f(*buffer, packets.keys, packets.grads)
+        f = self._smap(
+            _f, tuple(b_specs) + (pkt_specs.buffer_slot, pkt_specs.grads),
+            b_specs)
+        return f(*buffer, packets.buffer_slot, packets.grads)
 
     def writeback(self, table: EmbeddingTableState, buffer: DualBuffer) -> EmbeddingTableState:
         """DBP epilogue: scatter updated buffer rows back to the master shard."""
@@ -522,7 +547,7 @@ class EmbeddingEngine:
         """Serial-mode update: window packets -> shard row space (replica
         aligned) -> rowwise adagrad. Used by the non-DBP baseline."""
         t_specs = self._table_pspecs()
-        pkt_specs = self._stack(GradPacket(self._local_spec(), self._local_spec()))
+        pkt_specs = self._stack(self._packet_pspecs())
 
         def _f(rows, accum, pkeys, pgrads):
             local_idx = self._master_local_idx(pkeys).reshape(-1)
@@ -536,7 +561,8 @@ class EmbeddingEngine:
             new_rows, new_accum = self._rowwise_adagrad(rows, accum, total, touched)
             return EmbeddingTableState(new_rows, new_accum)
 
-        f = self._smap(_f, tuple(t_specs) + tuple(pkt_specs), t_specs)
+        f = self._smap(_f, tuple(t_specs) + (pkt_specs.keys, pkt_specs.grads),
+                       t_specs)
         return f(table.rows, table.accum, packets.keys, packets.grads)
 
     # -- metrics --------------------------------------------------------

@@ -225,25 +225,15 @@ def segment_rowsum(values: jax.Array, segment_ids: jax.Array, num_segments: int)
 def sorted_lookup(sorted_keys: jax.Array, queries: jax.Array) -> jax.Array:
     """Index of each query in a sorted sentinel-padded key buffer.
 
-    Returns len(sorted_keys) (== miss) for queries not present. Used for
-    buffer-resident lookups (DBP) and intersection sync.
+    Returns len(sorted_keys) (== miss) for queries not present. Used only by
+    :func:`intersect_sorted` (the DBP dual-buffer sync): the window's lookups
+    take each key's buffer slot from routing instead.
     """
     n = sorted_keys.shape[0]
     idx = jnp.searchsorted(sorted_keys, queries, side="left")
     idx_c = jnp.minimum(idx, n - 1)
     hit = (sorted_keys[idx_c] == queries) & (queries != SENTINEL)
     return jnp.where(hit, idx_c, n).astype(jnp.int32)
-
-
-def merge_sorted_unique(key_sets: jax.Array, out_cap: int) -> jax.Array:
-    """Union of several sentinel-padded key sets -> sorted unique (out_cap,).
-
-    ``key_sets``: any shape, flattened. Used to build the owner-side buffer
-    key list from per-micro-batch received key sets.
-    """
-    flat = key_sets.reshape(-1)
-    res = fixed_unique(flat, out_cap)
-    return res.unique_keys
 
 
 def intersect_sorted(keys_a: jax.Array, keys_b: jax.Array):

@@ -25,8 +25,9 @@ from repro.core.embedding.engine import EmbeddingEngine
 from repro.core.embedding.routing import (
     SENTINEL,
     bucket_by_owner_window,
+    fixed_unique,
     fixed_unique_window,
-    merge_sorted_unique,
+    sorted_lookup,
 )
 from repro.core.embedding.table import make_mega_table_spec
 from repro.utils import round_up
@@ -151,18 +152,55 @@ def test_route_window_equals_per_micro_batch_reference(n_micro, factor):
     recv_sets = []
     for i in range(n_micro):
         ref_plan = eng._route_one(jnp.asarray(keys[i]).reshape(-1), dims)
-        for got_leaf, ref_leaf in zip(
-            jax.tree.map(lambda x: x[i], window.plans), ref_plan
-        ):
-            np.testing.assert_array_equal(np.asarray(got_leaf),
-                                          np.asarray(ref_leaf))
+        got_plan = jax.tree.map(lambda x: x[i], window.plans)
+        # every leaf but buffer_slot, which indexes the window's union
+        # (test_buffer_slot_equals_search_of_buffer_keys checks it)
+        for field in ref_plan._fields:
+            if field != "buffer_slot":
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(got_plan, field)),
+                    np.asarray(getattr(ref_plan, field)))
         recv_sets.append(np.asarray(ref_plan.recv_keys).reshape(-1))
     if factor == 0.25:
         assert int(eng.overflow_metric(window)) > 0  # overflow path exercised
     # buffer keys are the sorted union of all received key sets
-    want_union = np.asarray(merge_sorted_unique(
-        jnp.asarray(np.concatenate(recv_sets)), dims.buffer_cap))
+    want_union = np.asarray(fixed_unique(
+        jnp.asarray(np.concatenate(recv_sets)), dims.buffer_cap).unique_keys)
     np.testing.assert_array_equal(np.asarray(window.buffer_keys), want_union)
+
+
+@pytest.mark.parametrize("case", [
+    "no_overflow", "unique_overflow", "bucket_overflow", "sentinel_heavy",
+    "psum_union",
+])
+def test_buffer_slot_equals_search_of_buffer_keys(case):
+    """The slot routing hands the window is, at every received position,
+    exactly what a binary search of the buffer keys finds (K for a sentinel
+    or a key that overflowed its capacity)."""
+    if case == "psum_union":
+        # the union over replicated axes needs a mesh: 8 virtual devices,
+        # in a process of its own (tests/scenarios/buffer_slot_multidev.py)
+        from test_multidevice import run_scenario
+
+        assert "BUFFER SLOT OK" in run_scenario("buffer_slot_multidev.py")
+        return
+    factor, slack = {"unique_overflow": (0.25, 4.0),
+                     "bucket_overflow": (2.0, 0.25)}.get(case, (2.0, 4.0))
+    spec, eng = make_engine(unique_capacity_factor=factor, bucket_slack=slack)
+    n_micro = 4
+    rng = np.random.default_rng(11)
+    keys = np.array(spec.scramble(jnp.asarray(
+        rng.integers(0, 512, size=(n_micro, 8, 4)).astype(np.int32))))
+    if case == "sentinel_heavy":
+        keys[rng.random(keys.shape) < 0.8] = SENTINEL
+    window = eng.route_window(jnp.asarray(keys), n_micro)
+    overflowed = case in ("unique_overflow", "bucket_overflow")
+    assert (int(eng.overflow_metric(window)) > 0) == overflowed
+    recv = window.plans.recv_keys
+    want = sorted_lookup(window.buffer_keys, recv.reshape(-1))
+    np.testing.assert_array_equal(
+        np.asarray(window.plans.buffer_slot),
+        np.asarray(want).reshape(recv.shape))
 
 
 def test_route_window_sort_count_does_not_scale_with_n():
@@ -212,3 +250,39 @@ def test_serial_lookup_reuses_fused_route():
     ref = eng._route_one(jnp.asarray(keys).reshape(-1), dims)
     for got_leaf, ref_leaf in zip(plan, ref):
         np.testing.assert_array_equal(np.asarray(got_leaf), np.asarray(ref_leaf))
+
+
+def test_window_lookup_and_update_search_nothing():
+    """Structural: the window's buffer lookups, gradient packets and buffer
+    update hold no search loop (a ``jnp.searchsorted`` lowers to a scan):
+    every buffer row they touch comes from the plan's ``buffer_slot``."""
+    def count_loops(jaxpr):
+        total = 0
+        for eqn in jaxpr.eqns:
+            total += eqn.primitive.name in ("scan", "while")
+            for v in eqn.params.values():
+                if hasattr(v, "jaxpr"):  # closed sub-jaxprs (pjit/shard_map)
+                    total += count_loops(getattr(v.jaxpr, "jaxpr", v.jaxpr))
+        return total
+
+    spec, eng = make_engine()
+    from repro.core.embedding import init_table_state
+
+    n_micro, mb_shape = 4, (8, 4)
+    keys = spec.scramble(jnp.arange(n_micro * 32, dtype=jnp.int32)
+                         .reshape((n_micro,) + mb_shape))
+    window = eng.route_window(keys, n_micro)
+    table = init_table_state(jax.random.PRNGKey(0), spec, None, ("model",))
+    buf = eng.retrieve(table, window)
+
+    def window_sparse(buf, plans):
+        packets = []
+        for i in range(n_micro):
+            plan = jax.tree.map(lambda x: x[i], plans)
+            emb = eng.lookup_from_buffer(buf, plan, mb_shape, n_micro)
+            packets.append(eng.grads_to_owner(plan, emb, mb_shape, n_micro))
+        pkts = jax.tree.map(lambda *xs: jnp.stack(xs), *packets)
+        return eng.apply_window_to_buffer(buf, pkts)
+
+    jaxpr = jax.make_jaxpr(window_sparse)(buf, window.plans)
+    assert count_loops(jaxpr.jaxpr) == 0

@@ -19,7 +19,6 @@ from repro.core.embedding.routing import (
     bucket_by_owner,
     fixed_unique,
     intersect_sorted,
-    merge_sorted_unique,
     sorted_lookup,
 )
 from repro.core.embedding.table import make_mega_table_spec
@@ -127,10 +126,14 @@ def test_scrambler_balances_zipf_unique_traffic():
 
 
 def test_merge_sorted_unique():
+    """The buffer-key union of several key sets is ``fixed_unique`` over
+    them flattened; its inverse is each position's buffer slot."""
     a = jnp.asarray(np.array([[3, 7, SENTINEL], [1, 3, 9]], np.int32))
-    out = np.asarray(merge_sorted_unique(a, 8))
+    res = fixed_unique(a.reshape(-1), 8)
+    out = np.asarray(res.unique_keys)
     reals = out[out != SENTINEL]
     np.testing.assert_array_equal(reals, [1, 3, 7, 9])
+    np.testing.assert_array_equal(np.asarray(res.inverse), [1, 2, 8, 0, 1, 3])
 
 
 def test_sorted_lookup_miss_and_hit():
