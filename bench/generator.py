@@ -1,15 +1,18 @@
-"""The training job's input: zipf item-id sequences, drawn from the seed.
+"""The training job's input, drawn from the seed.
 
-One general generator for every traffic file. A traffic file
-(``traffic/<name>.json``) gives the per-chip batch and the key skew
-(``zipf_a``); the configuration gives the sequence length and the item
-table's rows. Batch ``step`` of seed ``seed`` is the same array whatever was
-drawn before it, so the reference can draw the batches the program trained
-on without taking anything from the program.
+General pieces for every traffic file and backbone: :func:`zipf` draws
+skewed ids, :func:`item_batch` a batch of zipf id sequences, and
+:func:`program_stream` hands a backbone's batches (its ``batch``, see
+:func:`bench.spec.backbone`) to the program in the form its driver reads.
+A traffic file (``traffic/<name>.json``) gives the per-chip batch and the
+key skew (``zipf_a``); the configuration gives the sequence length and the
+table's rows. Batch ``step`` of seed ``seed`` is the same array whatever
+was drawn before it, so the reference can draw the batches the program
+trained on without taking anything from the program.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterator
+from typing import Callable, Dict, Iterator
 
 import numpy as np
 
@@ -40,16 +43,16 @@ def scramble(ids: np.ndarray, layout: Dict[str, int]) -> np.ndarray:
             % np.uint64(layout["rows"])).astype(np.int32)
 
 
-def program_stream(seed: int, start_step: int, *, batch: int, seq_len: int,
-                   n_items: int, zipf_a: float, layout: Dict[str, int],
+def program_stream(draw: Callable[[int], Dict[str, np.ndarray]],
+                   start_step: int, *, layout: Dict[str, int],
                    item_offset: int = 0) -> Iterator[Dict[str, np.ndarray]]:
-    """The batches in the form the program's driver reads: ``keys`` are
-    mega-table rows, ``raw_keys`` the item ids (for its clustering)."""
+    """The batches ``draw(step)`` in the form the program's driver reads:
+    ``keys`` are the mega-table rows of the ``ids``, ``raw_keys`` the ids
+    (for its clustering); the batch's further fields pass as they are."""
     step = start_step
     while True:
-        ids = item_batch(seed, step, batch=batch, seq_len=seq_len,
-                         n_items=n_items, zipf_a=zipf_a)
+        fields = dict(draw(step))
+        ids = fields.pop("ids")
         yield {"keys": scramble(ids + item_offset, layout),
-               "raw_keys": ids.astype(np.int32)}
+               "raw_keys": ids.astype(np.int32), **fields}
         step += 1
-

@@ -1,11 +1,12 @@
 """The benchmark's one contact with the program under test (``src/repro``).
 
 It builds a ``Session`` through the normal path (``Session.from_arch``,
-``mode="nestpipe"``: the DBP driver with the FWP window), hands it the
-weights and the master table made from the seed by :mod:`bench.reference`,
-feeds it the batches of :mod:`bench.generator`, and reads back what the
-comparison needs: each step's loss, the first gradient as the optimizers
-hold it after one step, and each leaf's change after three.
+``mode="nestpipe"``: the DBP driver with the FWP window) from the
+configuration's backbone module (``program_arch``), hands it the weights
+and the master table made from the seed by :mod:`bench.reference`, feeds
+it the backbone's batches through :mod:`bench.generator`, and reads back
+what the comparison needs: each step's loss, the first gradient as the
+optimizers hold it after one step, and each leaf's change after three.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
 
-from . import generator, reference
+from . import generator, reference, spec
 from .spec import ROOT, Cell
 
 
@@ -34,25 +35,6 @@ def import_program():
         raise ProgramMissing(
             f"the program (package repro under {src}) cannot be imported: {e}"
         ) from None
-
-
-def arch_spec(cell: Cell):
-    """The configuration file as the program's ``ArchSpec``, with each table
-    holding ``cell.chips`` chips' share of its rows."""
-    from repro.configs.base import RecsysModelConfig, SparseTableConfig
-    from repro.configs.registry import ArchSpec
-
-    c = cell.config
-    rows = reference.table_rows(c, cell.chips)
-    tables = tuple(SparseTableConfig(t["name"], vocab_size=r, dim=t["dim"])
-                   for t, r in zip(c["tables"], rows))
-    cfg = RecsysModelConfig(
-        name=c["name"], backbone=c["backbone"], tables=tables,
-        d_model=c["d_model"], n_layers=c["n_layers"], n_heads=c["n_heads"],
-        d_ff=c["d_ff"], seq_len=c["seq_len"], norm_eps=c["norm_eps"],
-        param_dtype=c["param_dtype"], compute_dtype=c["compute_dtype"],
-        zipf_a=cell.traffic["zipf_a"])
-    return ArchSpec(c["name"], "recsys", cfg, cfg)
 
 
 def make_mesh(cell: Cell):
@@ -75,8 +57,10 @@ def build_session(cell: Cell, seed: int):
 
     mesh = make_mesh(cell)
     opt = dict(cell.config["optimizer"])
+    arch = spec.backbone(cell.config).program_arch(cell.config, cell.chips,
+                                                   cell.traffic)
     sess = Session.from_arch(
-        arch_spec(cell), mode="nestpipe", global_batch=cell.global_batch,
+        arch, mode="nestpipe", global_batch=cell.global_batch,
         seq_len=cell.config["seq_len"], mesh=mesh,
         sparse_axes=("data", "model") if mesh is not None else None,
         n_micro=cell.traffic["fwp_microbatches"],
@@ -118,7 +102,8 @@ class Initial:
         self.dense_key, self.table_key = reference.seed_keys(seed)
         self.fcfg = reference.Frozen(cell.config)
         want = jax.eval_shape(wl.bundle.init_params, jax.random.PRNGKey(0))
-        got = reference.param_shapes(cell.config)
+        got = spec.backbone(cell.config).param_shapes(cell.config,
+                                                      cell.chips)
         want_s = jax.tree.map(lambda x: tuple(x.shape), want)
         if want_s != got:
             raise ValueError(
@@ -128,7 +113,7 @@ class Initial:
         if wl.mesh is not None:
             sh = wl.state_shardings(sess.optimizer)
             dense_sh, table_sh = sh.dense, sh.table
-        self._params = jax.jit(reference.init_params, static_argnums=1,
+        self._params = jax.jit(reference.init_params, static_argnums=(1, 2),
                                out_shardings=dense_sh)
         self._perms = [jnp.asarray(p) for p in _perms(sess, cell)]
         rows, dim = wl.spec.padded_rows, wl.spec.dim
@@ -148,7 +133,7 @@ class Initial:
         self._master = jax.jit(master, out_shardings=table_sh)
 
     def params(self):
-        return self._params(self.dense_key, self.fcfg)
+        return self._params(self.dense_key, self.fcfg, self.cell.chips)
 
     def master(self):
         return self._master(self.table_key, self._perms)
@@ -164,20 +149,22 @@ class Initial:
 
 @contextlib.contextmanager
 def bench_stream(sess, cell: Cell, seed: int) -> Iterator[None]:
-    """Feed the session the benchmark's batches: ``Session.train`` takes its
+    """Feed the session the backbone's batches: ``Session.train`` takes its
     stream from ``repro.api.session.resolve_stream``, which this replaces
     for the duration (the program has no argument for a stream)."""
     import repro.api.session as session_mod
 
-    s = sess.workload.spec
-    kw = dict(batch=cell.global_batch, seq_len=cell.config["seq_len"],
-              n_items=s.table_vocabs[0], zipf_a=cell.traffic["zipf_a"],
-              layout=_layout(sess), item_offset=s.table_offsets[0])
+    bb = spec.backbone(cell.config)
+
+    def draw(step):
+        return bb.batch(seed, step, cell.config, cell.traffic, cell.chips)
 
     def resolve(wl, data_seed=0, *, start_step=0, **_):
         if wl is not sess.workload:
             raise RuntimeError("stream asked for another workload")
-        return generator.program_stream(seed, start_step, **kw)
+        return generator.program_stream(
+            draw, start_step, layout=_layout(sess),
+            item_offset=sess.workload.spec.table_offsets[0])
 
     saved = session_mod.resolve_stream
     session_mod.resolve_stream = resolve
@@ -187,23 +174,23 @@ def bench_stream(sess, cell: Cell, seed: int) -> Iterator[None]:
         session_mod.resolve_stream = saved
 
 
-def _first_grads(mu, accum, *, b1, n_layers, dim):
+def _first_grads(mu, accum, *, b1, stacked, dim):
     """The first gradient's norms as the optimizers hold it after one step:
-    Adam's first moment over ``1 - b1``, and the item rows' from the
-    rowwise Adagrad accumulator (mean of squares per row)."""
+    Adam's first moment over ``1 - b1``, and the first table's rows' from
+    the rowwise Adagrad accumulator (mean of squares per row)."""
     import jax.numpy as jnp
 
-    g = {k: v / (1 - b1) for k, v in reference.leaf_norms(mu, n_layers).items()}
+    g = {k: v / (1 - b1) for k, v in reference.leaf_norms(mu, stacked).items()}
     g["table"] = jnp.sqrt(dim * jnp.sum(accum))
     return g
 
 
-def _changes(dense, dense0, rows, rows0, *, n_layers):
+def _changes(dense, dense0, rows, rows0, *, stacked):
     import jax
     import jax.numpy as jnp
 
     c = reference.leaf_norms(jax.tree.map(jnp.subtract, dense, dense0),
-                             n_layers)
+                             stacked)
     c["table"] = jnp.sqrt(jnp.sum(jnp.square(rows - rows0)))
     return c
 
@@ -217,16 +204,16 @@ def check_steps(sess, cell: Cell, init: Initial) -> Dict[str, Any]:
     import jax
 
     c = cell.config
-    nl = c["n_layers"]
-    first_grads = jax.jit(_first_grads, static_argnames=("b1", "n_layers",
+    stacked = spec.backbone(c).stacked(c)
+    first_grads = jax.jit(_first_grads, static_argnames=("b1", "stacked",
                                                           "dim"))
-    changes = jax.jit(_changes, static_argnames=("n_layers",))
+    changes = jax.jit(_changes, static_argnames=("stacked",))
     t0 = time.perf_counter()
     rep1 = sess.train(1)
     st = sess.state
     grads = {k: float(v) for k, v in first_grads(
-        st.opt.mu, st.table.accum, b1=c["optimizer"]["beta1"], n_layers=nl,
-        dim=sess.workload.spec.dim).items()}
+        st.opt.mu, st.table.accum, b1=c["optimizer"]["beta1"],
+        stacked=stacked, dim=sess.workload.spec.dim).items()}
     t1 = time.perf_counter()
     rep2 = sess.train(2)
     jax.block_until_ready(sess.state)
@@ -235,7 +222,7 @@ def check_steps(sess, cell: Cell, init: Initial) -> Dict[str, Any]:
     m0 = init.master()
     change = {k: float(v) for k, v in changes(
         st.dense, init.params(), st.table.rows, m0.rows,
-        n_layers=nl).items()}
+        stacked=stacked).items()}
     del m0
     return {
         "losses": list(rep1.stats.losses) + list(rep2.stats.losses),

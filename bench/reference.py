@@ -1,27 +1,18 @@
 """Plain float32 reference of the benchmark's training cells.
 
 Independent of the program: it imports nothing from ``repro``. From the
-configuration file and the seed it makes the dense weights and the item
-table, draws the batches with :mod:`bench.generator`, and trains serially:
-the whole batch's mean loss, its gradients computed in blocks of sequences,
-AdamW with global-norm clipping on the dense weights and rowwise Adagrad on
-the item rows that the batch touched. Matrix products run at
-``Precision.HIGHEST`` (``precision="highest"``).
+configuration file and the seed it makes the dense weights and the tables,
+draws the batches, and trains serially: the whole batch's mean loss, its
+gradients computed in blocks of sequences, AdamW with global-norm clipping
+on the dense weights and rowwise Adagrad on the rows of the first table
+that the batch touched. Matrix products run at ``Precision.HIGHEST``
+(``precision="highest"``).
 
-The backbones follow the published layer equations as the configuration
-file's ``backbone`` names them:
-
-- ``hstu`` (Zhai et al. 2024): ``U, V, Q, K = split(silu(LN(x) W_uvqk))``,
-  ``A = silu(Q K^T / sqrt(d_qk)) * causal / n``,
-  ``x + (LN(A V) * U) W_o``. No relative attention bias (not in the
-  program either).
-- ``fuxi`` (Ye et al. 2025), as far as the program reproduces it: causal
-  softmax self-attention with rotary positions, then the multi-stage
-  feed-forward ``v <- v * sigmoid(v0 W_k) + v`` over three orders.
-
-Both train on next-item InfoNCE over each sequence's own items
-(temperature from the configuration), with the item embeddings as the
-targets' input as well.
+What belongs to one model family (its weights' shapes, its layer
+equations and loss, its batches and the split of its stacked leaves) is in
+the configuration's ``bench/backbones/<backbone>.py``
+(:func:`bench.spec.backbone`); this module holds what every backbone
+shares.
 
 ``precision="fp8"`` is the control: every matrix product's operands are
 rounded to float8 (e4m3, one scale per tensor) first. ``keep`` leaves the
@@ -30,7 +21,7 @@ the first eighth of the batch the wrong rows, each id's next row, as a
 gather with a wrong index would (planted faults).
 
 The same weights are what the benchmark hands the program
-(:func:`init_params`, :func:`init_tables`), so both start from one state.
+(:func:`init_params`, :func:`init_table`), so both start from one state.
 """
 from __future__ import annotations
 
@@ -43,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import generator
+from . import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 PyTree = Any
@@ -60,43 +51,18 @@ def seed_keys(seed: int) -> Tuple[jax.Array, jax.Array]:
     return jax.random.PRNGKey(int(a)), jax.random.PRNGKey(int(b))
 
 
-def param_shapes(cfg: Dict[str, Any]) -> PyTree:
-    """The dense weights' shapes, by name (stacked over layers)."""
-    d, h, nl = cfg["d_model"], cfg["n_heads"], cfg["n_layers"]
-    demb = max(t["dim"] for t in cfg["tables"])
-    if cfg["backbone"] == "hstu":
-        dh = d // h
-        ln = lambda n: {"scale": (nl, n), "bias": (nl, n)}  # noqa: E731
-        return {
-            "layers": {"norm": ln(d), "w_uvqk": (nl, d, 4 * h * dh),
-                       "w_o": (nl, h * dh, d), "out_norm": ln(h * dh)},
-            "in_proj": (demb, d),
-            "final_norm": {"scale": (d,), "bias": (d,)},
-        }
-    if cfg["backbone"] == "fuxi":
-        f = cfg["d_ff"]
-        layer = {
-            "norm1": {"scale": (nl, d)},
-            "attn": {"wq": (nl, d, d), "wk": (nl, d, d), "wv": (nl, d, d),
-                     "wo": (nl, d, d)},
-            "norm2": {"scale": (nl, d)},
-            "w_up": (nl, d, f), "w_down": (nl, f, d),
-        }
-        for o in range(cfg["fi_orders"]):
-            layer[f"w_fi{o}"] = (nl, f, f)
-        return {"layers": layer, "in_proj": (demb, d),
-                "final_norm": {"scale": (d,)}}
-    raise ValueError(f"no reference for backbone {cfg['backbone']!r}")
-
-
 def _is_shape(x) -> bool:
     return isinstance(x, tuple) and all(isinstance(i, int) for i in x)
 
 
-def init_params(key: jax.Array, cfg: Dict[str, Any]) -> PyTree:
-    """Norm scales 1, biases 0, ``in_proj`` N(0, in_proj_scale^2), other
-    matrices N(0, 1/fan_in). Jit it: one call makes every leaf on device."""
-    shapes = param_shapes(cfg)
+def init_params(key: jax.Array, cfg: Dict[str, Any], chips: int) -> PyTree:
+    """The backbone's own ``init_params`` where it has one; else norm scales
+    1, biases 0, ``in_proj`` N(0, in_proj_scale^2), other matrices
+    N(0, 1/fan_in). Jit it: one call makes every leaf on device."""
+    bb = spec.backbone(cfg)
+    if hasattr(bb, "init_params"):
+        return bb.init_params(key, cfg, chips)
+    shapes = bb.param_shapes(cfg, chips)
     flat, tree = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=_is_shape)
     keys = jax.random.split(key, len(flat))
     out = []
@@ -128,7 +94,7 @@ def init_table(key: jax.Array, cfg: Dict[str, Any], chips: int,
 
 
 # ---------------------------------------------------------------------------
-# the model
+# building blocks of the backbones
 # ---------------------------------------------------------------------------
 
 
@@ -140,42 +106,26 @@ def _q8(x: jax.Array) -> jax.Array:
 
 
 def _einsum(precision: str):
-    def ein(spec, a, b):
+    def ein(eq, a, b):
         if precision == "fp8":
             a, b = _q8(a), _q8(b)
-        return jnp.einsum(spec, a, b, precision=HIGHEST,
+        return jnp.einsum(eq, a, b, precision=HIGHEST,
                           preferred_element_type=jnp.float32)
     return ein
 
 
-def _layernorm(p, x, eps):
+def layernorm(p, x, eps):
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
 
 
-def _rmsnorm(p, x, eps):
+def rmsnorm(p, x, eps):
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
                              + eps) * p["scale"]
 
 
-def _hstu_layer(p, x, cfg, ein):
-    b, s, d = x.shape
-    h = cfg["n_heads"]
-    dh = d // h
-    eps = cfg["norm_eps"]
-    mixed = jax.nn.silu(ein("bsd,de->bse", _layernorm(p["norm"], x, eps),
-                            p["w_uvqk"])).reshape(b, s, h, 4 * dh)
-    u, v, q, k = (mixed[..., i * dh:(i + 1) * dh] for i in range(4))
-    scores = ein("bqhd,bkhd->bhqk", q, k) / np.sqrt(dh)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    a = jnp.where(causal, jax.nn.silu(scores), 0.0) / s
-    y = ein("bhqk,bkhd->bqhd", a, v).reshape(b, s, d)
-    y = _layernorm(p["out_norm"], y, eps) * u.reshape(b, s, d)
-    return x + ein("bsd,de->bse", y, p["w_o"])
-
-
-def _rope(x, theta):
+def rope(x, theta):
     """Rotary positions on (b, s, h, hd), halves rotated as pairs."""
     s, hd = x.shape[1], x.shape[-1]
     freqs = 1.0 / theta ** (np.arange(0, hd, 2) / hd)
@@ -184,50 +134,6 @@ def _rope(x, theta):
     sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None]
     x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _fuxi_layer(p, x, cfg, ein):
-    b, s, d = x.shape
-    h = cfg["n_heads"]
-    hd = d // h
-    eps = cfg["norm_eps"]
-    a = p["attn"]
-    n = _rmsnorm(p["norm1"], x, eps)
-    q, k, v = (ein("bsd,de->bse", n, a[w]).reshape(b, s, h, hd)
-               for w in ("wq", "wk", "wv"))
-    q, k = _rope(q, cfg["rope_theta"]), _rope(k, cfg["rope_theta"])
-    scores = ein("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    w = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    o = ein("bhqk,bkhd->bqhd", w, v).reshape(b, s, d)
-    x = x + ein("bsd,de->bse", o, a["wo"])
-    n = _rmsnorm(p["norm2"], x, eps)
-    v0 = ein("bsd,df->bsf", n, p["w_up"])
-    v = v0
-    for o in range(cfg["fi_orders"]):
-        v = v * jax.nn.sigmoid(ein("bsf,fg->bsg", v0, p[f"w_fi{o}"])) + v
-    return x + ein("bsf,fd->bsd", v, p["w_down"])
-
-
-_LAYERS = {"hstu": (_hstu_layer, _layernorm), "fuxi": (_fuxi_layer, _rmsnorm)}
-
-
-def sequence_losses(params, emb, cfg, precision: str = "highest"):
-    """Per-sequence next-item InfoNCE of item embeddings ``emb`` (b, s, D)."""
-    ein = _einsum(precision)
-    layer, final_norm = _LAYERS[cfg["backbone"]]
-    x = ein("bse,ed->bsd", emb, params["in_proj"])
-    step = jax.checkpoint(lambda p, x: layer(p, x, cfg, ein))
-    for i in range(cfg["n_layers"]):
-        x = step(jax.tree.map(lambda w: w[i], params["layers"]), x)
-    hidden = final_norm(params["final_norm"], x, cfg["norm_eps"])
-    preds = hidden[:, :-1]
-    targets = ein("bse,ed->bsd", emb[:, 1:], params["in_proj"])
-    pf = preds / (jnp.linalg.norm(preds, axis=-1, keepdims=True) + 1e-6)
-    tf = targets / (jnp.linalg.norm(targets, axis=-1, keepdims=True) + 1e-6)
-    logits = ein("bqd,bkd->bqk", pf, tf) / cfg["temperature"]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.mean(jnp.diagonal(logp, axis1=1, axis2=2), axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -260,26 +166,31 @@ def _global_norm(tree):
 @functools.partial(jax.jit, static_argnames=("cfg", "block", "precision",
                                              "mesh", "alter"),
                    donate_argnums=(0, 1, 2, 3, 4))
-def _step(params, mu, nu, items, accum, ids, count, *, cfg, block,
+def _step(params, mu, nu, items, accum, ids, fields, count, *, cfg, block,
           precision, mesh, alter=False):
     opt, sopt = cfg["optimizer"], cfg["sparse_optimizer"]
+    losses = spec.backbone(cfg).losses
+    ein = _einsum(precision)
     batch, seq = ids.shape
     emb = items[ids]  # (B, S, D)
     if alter:  # an eighth of the batch served the next row of each id
         k = max(1, batch // 8)
         emb = emb.at[:k].set(items[(ids[:k] + 1) % items.shape[0]])
 
-    def block_loss(p, e):
-        return jnp.sum(sequence_losses(p, e, cfg, precision))
+    def block_loss(p, e, f):
+        return jnp.sum(losses(p, e, f, cfg, ein))
 
     grad = jax.value_and_grad(block_loss, argnums=(0, 1))
 
-    def body(acc, e):
-        loss, (gp, ge) = grad(params, e)
+    def body(acc, xs):
+        loss, (gp, ge) = grad(params, *xs)
         return (acc[0] + loss, jax.tree.map(jnp.add, acc[1], gp)), ge
 
     zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, params))
-    blocks = emb.reshape(batch // block, block, seq, -1)
+    # the embeddings and the batch's further fields, in blocks of sequences
+    blocks = jax.tree.map(
+        lambda x: x.reshape((batch // block, block) + x.shape[1:]),
+        (emb, fields))
     if mesh is not None:  # each block's sequences split over the chips
         blocks = jax.lax.with_sharding_constraint(
             blocks, NamedSharding(mesh, P(None, "b")))
@@ -314,13 +225,18 @@ def _step(params, mu, nu, items, accum, ids, count, *, cfg, block,
         jnp.sum(total * total))
 
 
-def leaf_norms(tree: PyTree, n_layers: int) -> Dict[str, jax.Array]:
-    """Norm of each leaf, the stacked layer leaves split per layer."""
+def leaf_norms(tree: PyTree, stacked) -> Dict[str, jax.Array]:
+    """Norm of each leaf; a leaf under a ``(prefix, count)`` pair of
+    ``stacked`` (the backbone's ``stacked(cfg)``) is split along its first
+    axis into ``count`` layers."""
+    counts = dict(stacked)
     out = {}
     for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        name = "/".join(str(getattr(k, "key", k)) for k in path)
-        if name.startswith("layers/"):
-            for i in range(n_layers):
+        name = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                        for k in path)
+        n = counts.get(name.split("/", 1)[0])
+        if n is not None:
+            for i in range(n):
                 out[f"{name}[{i}]"] = jnp.sqrt(jnp.sum(jnp.square(
                     x[i].astype(jnp.float32))))
         else:
@@ -333,12 +249,14 @@ def train(seed: int, cfg: Dict[str, Any], traffic_cfg: Dict[str, Any],
           keep: int = 0, alter: bool = False) -> Dict[str, Any]:
     """Train ``steps`` steps from the seed; returns the numbers the
     benchmark compares: each step's loss, the first step's gradient norms
-    as the optimizers get them (``"table"`` is the item rows' gradient) and
-    the norms of each leaf's change over the ``steps`` steps."""
+    as the optimizers get it (``"table"`` is the first table's rows'
+    gradient) and the norms of each leaf's change over the ``steps``
+    steps."""
+    bb = spec.backbone(cfg)
+    stacked = bb.stacked(cfg)
     dense_key, table_key = seed_keys(seed)
     batch = traffic_cfg["batch_per_chip"] * chips
     keep = keep or batch
-    seq = cfg["seq_len"]
     n_items = table_rows(cfg, chips)[0]
     fcfg = Frozen(cfg)
     # on several chips every chip holds the whole state and computes its
@@ -346,10 +264,11 @@ def train(seed: int, cfg: Dict[str, Any], traffic_cfg: Dict[str, Any],
     mesh = Mesh(np.asarray(jax.devices()[:chips]), ("b",)) \
         if chips > 1 else None
     rep = NamedSharding(mesh, P()) if mesh is not None else None
-    make_params = jax.jit(init_params, static_argnums=1, out_shardings=rep)
+    make_params = jax.jit(init_params, static_argnums=(1, 2),
+                          out_shardings=rep)
     make_items = jax.jit(init_table, static_argnums=(1, 2, 3),
                          out_shardings=rep)
-    params0 = make_params(dense_key, fcfg)
+    params0 = make_params(dense_key, fcfg, chips)
     params = jax.tree.map(jnp.copy, params0)
     mu = jax.tree.map(jnp.zeros_like, params)
     nu = jax.tree.map(jnp.zeros_like, params)
@@ -357,22 +276,22 @@ def train(seed: int, cfg: Dict[str, Any], traffic_cfg: Dict[str, Any],
     accum = jax.device_put(jnp.zeros((n_items,), jnp.float32), rep)
     losses, grads0, table_g0 = [], None, None
     for t in range(steps):
-        ids = generator.item_batch(
-            seed, t, batch=batch, seq_len=seq, n_items=n_items,
-            zipf_a=traffic_cfg["zipf_a"])[:keep]
+        fields = dict(bb.batch(seed, t, cfg, traffic_cfg, chips))
+        ids = jax.device_put(jnp.asarray(fields.pop("ids")[:keep],
+                                         jnp.int32), rep)
+        fields = {k: jax.device_put(jnp.asarray(v[:keep]), rep)
+                  for k, v in fields.items()}
         params, mu, nu, items, accum, loss, grads, tnorm = _step(
-            params, mu, nu, items, accum,
-            jax.device_put(jnp.asarray(ids, jnp.int32), rep),
+            params, mu, nu, items, accum, ids, fields,
             jnp.float32(t + 1), cfg=fcfg, block=min(block, keep),
             precision=precision, mesh=mesh, alter=alter)
         losses.append(float(loss))
         if t == 0:
             grads0 = {k: float(v) for k, v in
-                      leaf_norms(grads, cfg["n_layers"]).items()}
+                      leaf_norms(grads, stacked).items()}
             table_g0 = float(tnorm)
     change = {k: float(v) for k, v in leaf_norms(
-        jax.tree.map(jnp.subtract, params, params0),
-        cfg["n_layers"]).items()}
+        jax.tree.map(jnp.subtract, params, params0), stacked).items()}
     items0 = make_items(table_key, fcfg, chips, 0)
     change["table"] = float(jnp.sqrt(jnp.sum(jnp.square(items - items0))))
     grads0["table"] = table_g0

@@ -1,10 +1,16 @@
 """Find a cell's files by the names ``BENCHMARK.json`` gives them.
 
-Nothing here knows a particular configuration, traffic mix or metric: a new
-one is a new file plus a new ``BENCHMARK.json`` entry.
+Nothing here knows a particular configuration, backbone, traffic mix or
+metric: each is found by its name, so a new one is new files plus new
+``BENCHMARK.json`` entries. A configuration of a backbone the benchmark
+already has brings ``configs/<config>.json``; one of a new backbone also
+brings ``backbones/<backbone>.py`` (:func:`backbone`) and
+``flops/<backbone>.py`` (:func:`flops_per_sample`); a cell brings
+``workloads/<cell>.json`` and, for a new mix, ``traffic/<traffic>.json``.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -98,6 +104,34 @@ def metric_reader(name: str, root: str = ROOT) -> Callable:
     mod = _load_module(os.path.join(root, "bench", "metrics", name + ".py"),
                        "bench_metric_" + name.replace(".", "_").replace("-", "_"))
     return mod.read
+
+
+@functools.lru_cache(maxsize=None)
+def _backbone(path: str, name: str):
+    return _load_module(path, "bench_backbone_" + name.replace("-", "_"))
+
+
+def backbone(config: Dict[str, Any], root: str = ROOT):
+    """The module ``bench/backbones/<backbone>.py`` of the configuration's
+    ``backbone``: everything the harness knows of one model family. It has
+
+    - ``param_shapes(cfg, chips)``: the program's dense weights' shapes, by
+      name, as the program's tree holds them;
+    - ``init_params(key, cfg, chips)`` (optional): the dense weights, where
+      ``reference.init_params``'s rule cannot make them;
+    - ``losses(params, emb, batch, cfg, ein)``: each sequence's loss, from
+      the rows ``emb`` (B, S, D) of the ``ids`` and the batch's further
+      fields, with ``ein`` for every matrix product;
+    - ``batch(seed, step, cfg, traffic, chips)``: training batch ``step``:
+      ``ids`` (B, S) into the first table, and any further field the
+      program's loss reads, each with the batch on its first axis;
+    - ``program_arch(cfg, chips, traffic)``: the program's ``ArchSpec``;
+    - ``stacked(cfg)``: ``(prefix, count)`` pairs: the dense leaves under
+      each prefix are stacked over ``count`` layers, and compared per layer.
+    """
+    name = config["backbone"]
+    return _backbone(os.path.join(root, "bench", "backbones", name + ".py"),
+                     name)
 
 
 def flops_per_sample(config: Dict[str, Any], root: str = ROOT) -> float:

@@ -18,16 +18,19 @@ TOL = {"loss_gap": 1e-5, "grad_gap": 1e-5, "change_gap": 1e-4,
 
 def preset_cell(name: str, batch: int = 16) -> spec.Cell:
     """A cell at the program's reduced preset ``name``, with the bench
-    settings of the full configuration of the same backbone."""
+    settings of the configuration file of the preset's backbone."""
     program.import_program()
-    from repro.configs.registry import get_arch
+    from repro.configs.registry import RECSYS_ARCHS, get_arch
 
-    pre = get_arch(name.replace("-reduced", "-industrial")
-                   if name.startswith("hstu") else
-                   name.replace("-reduced", "-kuairand")).reduced
-    full = "hstu-industrial" if pre.backbone == "hstu" else "fuxi-kuairand"
-    with open(os.path.join(HERE, "configs", full + ".json")) as f:
-        cfg = json.load(f)
+    pre = next(a.reduced for a in map(get_arch, RECSYS_ARCHS)
+               if a.reduced.name == name)
+    for fname in sorted(os.listdir(os.path.join(HERE, "configs"))):
+        with open(os.path.join(HERE, "configs", fname)) as f:
+            cfg = json.load(f)
+        if cfg["backbone"] == pre.backbone:
+            break
+    else:
+        raise LookupError(f"no configuration of backbone {pre.backbone!r}")
     cfg.update(
         d_model=pre.d_model, n_layers=pre.n_layers, n_heads=pre.n_heads,
         d_ff=pre.d_ff, seq_len=pre.seq_len, norm_eps=pre.norm_eps,
